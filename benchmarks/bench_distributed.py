@@ -142,6 +142,13 @@ def main(emit=print, lubm_queries=LUBM_QUERIES, sp2b_queries=SP2B_QUERIES,
     import jax
     if jax.device_count() >= NUM_SHARDS:
         return _mesh_main(emit, lubm_queries, sp2b_queries, repeats)
+    if jax.default_backend() != "cpu":
+        # forced host devices are a CPU stand-in: under an accelerator's
+        # run they would report CPU numbers (and the parent holds the chip)
+        print(f"bench_distributed: skipped: it needs {NUM_SHARDS} devices "
+              f"and this {jax.default_backend()} host has "
+              f"{jax.device_count()}", file=sys.stderr)
+        return
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={NUM_SHARDS}"

@@ -17,7 +17,9 @@ clock driven by measured wall times:
               batcher absorbs it with moderate batches;
   coldstart — first-contact cost: the sequential loop compiles one
               cascade PER DISTINCT QUERY (constants are baked into the
-              plan), the engine one per (template, batch-shape).
+              plan), the engine one per (template, batch-shape). The
+              persistent compile cache is off for this phase, so the
+              row times compiles whatever the cache holds.
 
 A fourth phase measures the PRODUCTION shape (PR 4): `sharded` runs the
 same kind of mixed stream through a ServeEngine bound to a forced
@@ -50,6 +52,7 @@ import numpy as np
 
 import jax
 
+from repro.common import compile_cache_off
 from repro.core import (Caps, ExecConfig, build_store, execute_local,
                         execute_oracle, rows_set)
 from repro.core.bgp import order_patterns
@@ -418,7 +421,17 @@ def _chaos_mesh_main(emit=print, num_shards=2, lubm_scale=1, seed=0,
 def _respawn_forced(spec: dict, num_shards: int, emit):
     """Re-run this module in a subprocess with forced host devices (the
     device-count flag must never leak into the caller's jax), re-emitting
-    the child's bench rows."""
+    the child's bench rows. Forced host devices stand in for a mesh only
+    on a CPU parent: on an accelerator with too few devices the phase is
+    skipped and emits no row — a CPU child would report CPU numbers under
+    the accelerator's run (and the parent already holds the chip)."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        phase = "chaos" if spec.get("chaos") else "sharded"
+        print(f"bench_serving: skipped the {phase} phase: it needs "
+              f"{num_shards} devices and this {backend} host has "
+              f"{jax.device_count()}", file=sys.stderr)
+        return
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count"
@@ -490,14 +503,16 @@ def main(emit=print, lubm_scale=2, sp2b_scale=1000, n_requests=192,
                 for t in stores}
 
     # --- cold start (compiles included), then warm both paths -------------
+    # the persistent compile cache is kept out: these rows time compiles
     engines = fresh_engines()
     zero = [0.0] * n_requests
-    t0 = time.perf_counter()
-    _run_batched(engines, reqs, zero)
-    cold_batched = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    _run_sequential(stores, reqs, zero)
-    cold_seq = time.perf_counter() - t0
+    with compile_cache_off():
+        t0 = time.perf_counter()
+        _run_batched(engines, reqs, zero)
+        cold_batched = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        _run_sequential(stores, reqs, zero)
+        cold_seq = time.perf_counter() - t0
     # deterministic warm-up: every template at every pow2 batch shape, so
     # neither timed phase below ever waits on a compile (a deployment
     # would do this from a traffic log at startup — ServeEngine.precompile)

@@ -102,6 +102,8 @@ def run_suite(name: str, mod, emit=print, meta: dict | None = None) -> str:
 
 def main() -> None:
     args = [a for a in sys.argv[1:]]
+    from repro.common import enable_compile_cache
+    enable_compile_cache()
     if "--smoke" in args:
         from benchmarks import smoke
         raise SystemExit(smoke.main())

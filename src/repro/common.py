@@ -1,8 +1,10 @@
 """Shared small utilities used across the framework."""
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
+import os
 from typing import Any, Iterator
 
 import jax
@@ -87,3 +89,37 @@ def ceil_div(a: int, b: int) -> int:
 
 def round_up(a: int, b: int) -> int:
     return ceil_div(a, b) * b
+
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    already keeps the cache there and no directory is set here; otherwise
+    it is the fixed ``<repo>/.jax_cache`` (a fixed path: the path is part
+    of the cache key, so a moving directory would never hit). Every
+    compile is cached, however short. Call it from an entry point, never
+    at import time."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(REPO_ROOT, ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
+
+
+@contextlib.contextmanager
+def compile_cache_off():
+    """Keep JAX's persistent compilation cache out of a block, so that a
+    block which times compiles measures compiles, not cache reads."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()

@@ -52,6 +52,11 @@ class ExecConfig:
                                  # (a2a = point-to-point region routing)
     reorder: bool = True         # False = execute patterns as given
 
+    def __post_init__(self):
+        # an unknown impl would otherwise run jnp without saying so
+        if self.impl not in ("jnp", "pallas", "pallas_interpret"):
+            raise ValueError(f"unknown impl {self.impl!r}")
+
 
 @dataclasses.dataclass(frozen=True)
 class Step:

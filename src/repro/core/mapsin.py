@@ -90,10 +90,14 @@ def compact(rows: jnp.ndarray, valid: jnp.ndarray, out_cap: int,
 
 def searchsorted(keys: jnp.ndarray, queries: jnp.ndarray,
                  impl: str = "jnp") -> jnp.ndarray:
-    if impl == "pallas_interpret":
-        from repro.kernels import ops
-        return ops.searchsorted(keys, queries, interpret=True)
-    return jnp.searchsorted(keys, queries)
+    """'left' ranks of `queries` in the sorted `keys`: ``jnp.searchsorted``
+    for impl="jnp", the Pallas kernel (kernels/searchsorted.py) compiled
+    for impl="pallas" and interpreted for impl="pallas_interpret"."""
+    if impl == "jnp":
+        return jnp.searchsorted(keys, queries)
+    from repro.kernels import ops
+    return ops.searchsorted(keys, queries,
+                            interpret=(impl == "pallas_interpret"))
 
 
 def gather_range(keys: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,

@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 from collections import OrderedDict
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -104,6 +105,22 @@ class TripleStore:
         if key not in self.plan_cache:
             self.plan_cache[key] = self.keys(index).reshape(-1)
         return self.plan_cache[key]
+
+    def shard_onto(self, mesh, axis: str) -> None:
+        """Place each index's region rows on the devices of `mesh` along
+        `axis` (region k on the k-th device), so a sharded executor reads
+        its regions where they sit instead of copying them out of one
+        device on every call. A no-op when they are placed already; the
+        cached flat views are dropped (they were views of the old
+        arrays)."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        sharding = NamedSharding(mesh, PartitionSpec(axis, None))
+        if getattr(self.keys_spo, "sharding", None) == sharding:
+            return
+        self.keys_spo = jax.device_put(self.keys_spo, sharding)
+        self.keys_ops = jax.device_put(self.keys_ops, sharding)
+        for index in (SPO, OPS):
+            self.plan_cache.pop(("flat_keys", index), None)
 
     def splits(self, index: int) -> jnp.ndarray:
         return self.splits_spo if index == SPO else self.splits_ops

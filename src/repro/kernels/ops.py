@@ -1,9 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU container kernels run with interpret=True (Mosaic custom calls
-do not lower on the CPU backend); on TPU the same entry points compile
-natively. The jnp fallbacks in models/ and core/ are numerically identical
-(validated in tests/test_kernels_*.py).
+The kernels compile natively for TPU by default (``interpret=False``).
+Mosaic custom calls do not lower on the CPU backend, so a CPU caller must
+ask for the interpreter explicitly (``interpret=True``, or
+``ExecConfig(impl="pallas_interpret")``) — nothing here falls back to it on
+its own. The jnp paths in models/ and core/ are numerically identical
+(validated in tests/test_kernels_*.py and tests/test_probe_gather.py).
 """
 from __future__ import annotations
 
@@ -31,7 +33,7 @@ def unpack_to_cols(keys: jax.Array) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_k", "block_q"))
 def searchsorted(keys: jax.Array, queries: jax.Array, *,
-                 interpret: bool = True, block_k: int = 2048,
+                 interpret: bool = False, block_k: int = 2048,
                  block_q: int = 256) -> jax.Array:
     """Drop-in for jnp.searchsorted(keys, queries) on packed int64 keys."""
     return _ss.searchsorted3(unpack_to_cols(keys), unpack_to_cols(queries),
@@ -45,7 +47,7 @@ def searchsorted(keys: jax.Array, queries: jax.Array, *,
 def probe_gather(keys: jax.Array, lo: jax.Array, hi: jax.Array,
                  flt: jax.Array, *, cap: int,
                  flt_mask: tuple = (False, False, False),
-                 eq_positions: tuple = (), interpret: bool = True,
+                 eq_positions: tuple = (), interpret: bool = False,
                  block_k: int = 2048, block_q: int = 256):
     """Fused MAPSIN probe on packed int64 keys — drop-in for the jnp
     gather_range + apply_residual pair in core/mapsin.py `probe`.
@@ -65,7 +67,7 @@ def probe_gather(keys: jax.Array, lo: jax.Array, hi: jax.Array,
 
 @functools.partial(jax.jit,
                    static_argnames=("causal", "interpret", "block_q", "block_kv"))
-def flash_attention(q, k, v, *, causal: bool = True, interpret: bool = True,
+def flash_attention(q, k, v, *, causal: bool = True, interpret: bool = False,
                     block_q: int = 512, block_kv: int = 512):
     return _fa.flash_attention(q, k, v, causal=causal, block_q=block_q,
                                block_kv=block_kv, interpret=interpret)

@@ -937,6 +937,9 @@ class ServeEngine:
             return (np.asarray(out.table)[None], np.asarray(out.valid)[None],
                     np.asarray(out.overflow)[None],
                     np.asarray(step_ovf)[None], 0)
+        # regions live on their mesh devices (re-placed after a mutation
+        # re-materialized the store's arrays)
+        self.store.shard_onto(self.mesh, self.axis)
         with bracket:
             t, v, o, so, bad = jitted(self.store.keys_spo,
                                       self.store.keys_ops,

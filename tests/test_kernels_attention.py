@@ -25,7 +25,8 @@ def test_pallas_flash_vs_ref(case, dtype, rng):
     q = jnp.asarray(rng.randn(b, sq, h, e), dtype)
     k = jnp.asarray(rng.randn(b, skv, g, e), dtype)
     v = jnp.asarray(rng.randn(b, skv, g, e), dtype)
-    got = ops.flash_attention(q, k, v, causal=causal, block_q=64, block_kv=64)
+    got = ops.flash_attention(q, k, v, causal=causal, block_q=64, block_kv=64,
+                              interpret=True)
     want = ref.attention_ref(q, k, v, causal=causal)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     assert float(jnp.max(jnp.abs(got.astype(jnp.float32) -

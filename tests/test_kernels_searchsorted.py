@@ -20,7 +20,8 @@ def test_packed_sweep(m, q, rng):
     qs = pack3(rng.randint(0, 2100, q), rng.randint(0, 55, q),
                rng.randint(0, 2100, q))
     import jax.numpy as jnp
-    got = np.asarray(ops.searchsorted(jnp.asarray(keys), jnp.asarray(qs)))
+    got = np.asarray(ops.searchsorted(jnp.asarray(keys), jnp.asarray(qs),
+                                      interpret=True))
     want = np.searchsorted(keys, qs)
     np.testing.assert_array_equal(got, want)
 
@@ -57,7 +58,8 @@ def test_property_vs_oracle(seed, m, q):
                rng.randint(0, 90, q))
     import jax.numpy as jnp
     got = np.asarray(ops.searchsorted(jnp.asarray(keys), jnp.asarray(qs),
-                                      block_k=64, block_q=32))
+                                      block_k=64, block_q=32,
+                                      interpret=True))
     np.testing.assert_array_equal(got, np.searchsorted(keys, qs))
 
 
@@ -67,5 +69,6 @@ def test_boundary_duplicates():
     keys = np.array([5, 5, 5, 7, 7, 9], np.int64)
     qs = np.array([4, 5, 6, 7, 8, 9, 10], np.int64)
     got = np.asarray(ops.searchsorted(jnp.asarray(keys), jnp.asarray(qs),
-                                      block_k=64, block_q=32))
+                                      block_k=64, block_q=32,
+                                      interpret=True))
     np.testing.assert_array_equal(got, np.searchsorted(keys, qs))

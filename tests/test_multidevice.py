@@ -15,9 +15,11 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def run_in_subprocess(code: str) -> dict:
+    # the forced devices are host devices: pin the child to the CPU so it
+    # never reaches for an accelerator its parent may hold
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=SRC)
+               JAX_PLATFORMS="cpu", PYTHONPATH=SRC)
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-4000:]

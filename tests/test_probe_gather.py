@@ -173,3 +173,21 @@ def test_full_engine_pallas_interpret_vs_oracle():
         got = set(tuple(r[i] for i in perm) for r in got)
     assert int(bnd.overflow) == 0
     assert got == want
+
+
+def test_pallas_impl_never_falls_back():
+    """impl="pallas" means the compiled kernel: where Mosaic cannot lower
+    (this CPU backend) it raises rather than quietly running jnp or the
+    interpreter; an unknown impl is refused outright."""
+    import jax
+    from repro.core.mapsin import searchsorted
+    keys = jnp.asarray(np.arange(0, 100, 3, dtype=np.int64))
+    qs = jnp.asarray(np.array([4, 50], np.int64))
+    np.testing.assert_array_equal(
+        np.asarray(searchsorted(keys, qs, impl="pallas_interpret")),
+        np.searchsorted(np.asarray(keys), np.asarray(qs)))
+    if jax.default_backend() == "cpu":
+        with pytest.raises(ValueError, match="interpret"):
+            jax.block_until_ready(searchsorted(keys, qs, impl="pallas"))
+    with pytest.raises(ValueError, match="unknown impl"):
+        ExecConfig(impl="pallas_tpu")
