@@ -6,9 +6,11 @@ Perfetto (https://ui.perfetto.dev) and ``chrome://tracing`` load
 directly. Two kinds of span, matching the two shapes of serving work:
 
 * **track spans** (``async_id=None``) — engine-side work that happens
-  strictly nested on a logical thread: ``submit``, ``step``,
-  ``dispatch``, ``compile``, per-cascade-step work. Exported as ``ph:
-  "X"`` complete events on one trace thread per ``track`` name.
+  strictly nested on a logical thread: ``submit`` (with ``plan`` when
+  the planner ran), ``step``, and under it ``dispatch`` with its phases
+  ``dispatch.launch`` / ``dispatch.wait`` / ``dispatch.fetch``, then
+  ``deliver``. Exported as ``ph: "X"`` complete events on one trace
+  thread per ``track`` name.
 * **async spans** (``async_id=<query rid>``) — per-query lifecycle
   intervals that OUTLIVE any single engine call: the root ``query``
   span (submit -> delivery), its ``queued`` waits and per-attempt
@@ -21,7 +23,10 @@ append plain ``Span`` records stamped with a monotonic clock
 (``time.perf_counter``); nothing is formatted until ``export``. The
 serving engine holds ``tracer=None`` by default and guards every hook
 with one ``is not None`` test — the off path adds no work (overhead
-policy: DESIGN.md §8).
+policy: DESIGN.md §8). To line spans up with a ``jax.profiler`` trace,
+read ``now()`` inside two profiler annotations, one at each end of the
+profiled interval: the pair maps this clock onto the profiler's
+(``bench/spans.map_spans``).
 """
 from __future__ import annotations
 

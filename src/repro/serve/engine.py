@@ -315,6 +315,13 @@ def _rung_name(attempt: int) -> str:
             else f"rung{attempt}")
 
 
+def _scope(i: int, kind: str) -> str:
+    """Name scope of cascade step `i` (0 is the seed scan): trace-time
+    metadata that puts ``cascade/s<i>_<kind>`` into the HLO ``op_name`` of
+    the step's device ops, so a profile attributes device time by step."""
+    return f"cascade/s{i}_{kind}"
+
+
 # shared attrs dict for the per-query admission span: a successful submit
 # carries no per-query payload (the root "query" span holds it), so every
 # submit span can alias ONE dict instead of allocating its own
@@ -784,16 +791,9 @@ class ServeEngine:
         m = self.metrics_registry
         if hit is None:
             m.counter("serve_compile_cache_misses_total").inc()
-            tr = self.tracer
-            tc0 = tr.now() if tr is not None else 0.0
             hit = (self._build_sharded(template, batch, bucket_cap,
                                        step_caps, fsel, with_check)
                    if self.mesh is not None else self._build(template, batch))
-            if tr is not None:
-                # the jit wrapper build; XLA's lazy compile lands inside
-                # the first dispatch span that uses it
-                tr.record("compile", tc0, tr.now(), track="engine",
-                          parent=self._step_span, template=tid, batch=batch)
             self._compiled[key] = hit
             m.gauge("serve_compile_cache_size").set(len(self._compiled))
         else:
@@ -810,19 +810,23 @@ class ServeEngine:
         def one(keys_spo, keys_ops, consts, scratch):
             keys_of = lambda pat, dom: (
                 keys_spo if make_plan(pat, dom).index == 0 else keys_ops)
-            bnd = _seed_scan(first, const_vars, keys_of(first, const_vars),
-                             consts, steps[0].caps.out_cap, cfg.impl,
-                             scratch)
+            with jax.named_scope(_scope(0, "scan")):
+                bnd = _seed_scan(first, const_vars,
+                                 keys_of(first, const_vars), consts,
+                                 steps[0].caps.out_cap, cfg.impl, scratch)
             ovfs = [bnd.overflow]
-            for st in steps[1:]:
+            for i, st in enumerate(steps[1:], 1):
                 c = st.caps
                 keys = keys_of(st.patterns[0], bnd.vars)
-                if st.kind == "multiway":
-                    bnd = ms.multiway_step(bnd, st.patterns, keys,
-                                           c.row_cap, c.out_cap, cfg.impl)
-                else:
-                    bnd = ms.mapsin_step(bnd, st.patterns[0], keys,
-                                         c.probe_cap, c.out_cap, cfg.impl)
+                with jax.named_scope(_scope(i, st.kind)):
+                    if st.kind == "multiway":
+                        bnd = ms.multiway_step(bnd, st.patterns, keys,
+                                               c.row_cap, c.out_cap,
+                                               cfg.impl)
+                    else:
+                        bnd = ms.mapsin_step(bnd, st.patterns[0], keys,
+                                             c.probe_cap, c.out_cap,
+                                             cfg.impl)
                 ovfs.append(bnd.overflow)
             return bnd, jnp.stack(ovfs)          # cumulative, per step
 
@@ -880,19 +884,21 @@ class ServeEngine:
                 splits_spo if make_plan(pat, dom).index == 0 else splits_ops)
             seed_keys = keys_of(first, const_vars)
             scr = self._scratch(scratch_vars, batch, out_cap)
-            bnd = jax.vmap(
-                lambda c, s: _seed_scan(first, const_vars, seed_keys, c,
-                                        out_cap, cfg.impl,
-                                        s))(consts, scr)
+            with jax.named_scope(_scope(0, "scan")):
+                bnd = jax.vmap(
+                    lambda c, s: _seed_scan(first, const_vars, seed_keys, c,
+                                            out_cap, cfg.impl,
+                                            s))(consts, scr)
             ovfs = [bnd.overflow]
             bad = jnp.zeros((), jnp.int32)
             for i, st in enumerate(eff_steps[1:]):
                 keys = keys_of(st.patterns[0], bnd.vars)
-                out = apply_dist_step(
-                    bnd, st, keys, splits_of(st.patterns[0], bnd.vars),
-                    cfg, axis, batched=True,
-                    fault=fsel[i] if fsel is not None else None,
-                    with_check=with_check)
+                with jax.named_scope(_scope(i + 1, st.kind)):
+                    out = apply_dist_step(
+                        bnd, st, keys, splits_of(st.patterns[0], bnd.vars),
+                        cfg, axis, batched=True,
+                        fault=fsel[i] if fsel is not None else None,
+                        with_check=with_check)
                 if with_check:
                     bnd, bad_i = out
                     bad = bad + bad_i
@@ -913,12 +919,15 @@ class ServeEngine:
 
     def _dispatch(self, tid: int, template: Template, batch: int,
                   consts: np.ndarray, bucket_cap: int, step_caps: tuple,
-                  fsel=None, with_check: bool = False):
+                  fsel=None, with_check: bool = False,
+                  span: Span | None = None):
         """Run one compiled batched cascade; returns per-shard numpy views
         (tables (S, batch, out_cap, nv), valids (S, batch, out_cap),
         overflow (S, batch), step_ovf (S, batch, n_steps) cumulative, and
         the int quarantined-block count `bad`) — S == 1 and bad == 0 on
-        the local (mesh-less) path."""
+        the local (mesh-less) path. With a `span` (the open ``dispatch``
+        span of a traced engine) the phases become its children: see
+        ``_fetch``."""
         jitted, scratch_vars = self._compiled_batch(
             tid, template, batch, bucket_cap, step_caps, fsel, with_check)
         # optional jax.profiler bracket: lines the engine dispatch up with
@@ -934,6 +943,8 @@ class ServeEngine:
                                        jnp.asarray(consts),
                                        self._scratch(scratch_vars, batch,
                                                      out_cap))
+            if span is not None:
+                out, step_ovf = self._fetch(span, (out, step_ovf))
             return (np.asarray(out.table)[None], np.asarray(out.valid)[None],
                     np.asarray(out.overflow)[None],
                     np.asarray(step_ovf)[None], 0)
@@ -944,11 +955,32 @@ class ServeEngine:
             t, v, o, so, bad = jitted(self.store.keys_spo,
                                       self.store.keys_ops,
                                       jnp.asarray(consts))
+        if span is not None:
+            t, v, o, so, bad = self._fetch(span, (t, v, o, so, bad))
         self.a2a_payload_bytes += self._payload_bytes(bucket_cap, step_caps)
         # (S, n_steps, batch) -> (S, batch, n_steps)
         return (np.asarray(t), np.asarray(v), np.asarray(o),
                 np.transpose(np.asarray(so), (0, 2, 1)),
                 int(np.asarray(bad).sum()))
+
+    def _fetch(self, span: Span, outs):
+        """The traced dispatch's device half, as children of `span`:
+        ``dispatch.launch`` from the span's start until the jitted call
+        returned (cascade lookup, scratch, constants, enqueue),
+        ``dispatch.wait`` for the device to finish, ``dispatch.fetch``
+        for the copy of every output array to the host (attr ``bytes``).
+        Returns `outs` with numpy leaves, so the ``np.asarray`` calls
+        that follow copy nothing."""
+        tr = self.tracer
+        t_launched = tr.now()
+        tr.record("dispatch.launch", span.t0, t_launched, parent=span)
+        jax.block_until_ready(outs)
+        t_done = tr.now()
+        tr.record("dispatch.wait", t_launched, t_done, parent=span)
+        outs = jax.tree.map(np.asarray, outs)
+        tr.record("dispatch.fetch", t_done, tr.now(), parent=span,
+                  bytes=sum(x.nbytes for x in jax.tree.leaves(outs)))
+        return outs
 
     def precompile(self, query, batches: Sequence[int] | None = None):
         """Compile (and warm) the query's template cascade for the given
@@ -1147,7 +1179,7 @@ class ServeEngine:
             # (S, batch, out_cap, nv) per-shard tables; S == 1 un-meshed
             tables, valids, overflow, step_ovf, bad = self._dispatch(
                 reqs[0].tid, template, batch, consts, bucket_cap,
-                step_caps, fsel, with_check)
+                step_caps, fsel, with_check, dsp)
             if dsp is not None:
                 tr.end(dsp, bad=bad)
             if probe_b:
@@ -1186,8 +1218,13 @@ class ServeEngine:
             m.counter("serve_fault_unrecovered_total").inc()
         # delivery: rung + root spans materialize HERE, one shared `td`
         # clock read and one shared attrs dict per (attempt, outcome) —
-        # nothing span-shaped is allocated per query before this point
-        td = tr.now() if tr is not None else 0.0
+        # nothing span-shaped is allocated per query before this point;
+        # `td` also opens the `deliver` span
+        if tr is not None:
+            td = tr.now()
+            esc0 = self.escalations
+        else:
+            td = 0.0
         r_shared: dict = {}
         results = []
         for i, r in enumerate(reqs):
@@ -1296,6 +1333,11 @@ class ServeEngine:
                         "serve_tenant_latency_seconds",
                         tenant=str(r.tenant))
                 h.observe(lat)
+        if tr is not None:
+            tr.record("deliver", td, tr.now(), parent=self._step_span, n=n)
+            # requests of this bucket re-enqueued at larger caps: the
+            # share of the dispatch whose answers were thrown away
+            dsp.attrs["escalated"] = self.escalations - esc0
         return results
 
     # --- scheduling ------------------------------------------------------

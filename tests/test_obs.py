@@ -250,6 +250,110 @@ def test_engine_trace_exports_loadable_json(rng, tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# dispatch phases: launch / wait / fetch under dispatch, deliver under step
+# ---------------------------------------------------------------------------
+
+PAIR = [Pattern("?x", 101, "?y")]
+ROOMY = Caps(out_cap=512, probe_cap=64, row_cap=64)
+
+
+def _waited_on(monkeypatch):
+    """Record the output trees the engine waits on (jax.block_until_ready
+    is its only sync) and the bytes of their array leaves."""
+    import jax
+    seen = []
+    real = jax.block_until_ready
+
+    def wait(x):
+        seen.append(sum(a.nbytes for a in jax.tree.leaves(x)))
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", wait)
+    return seen
+
+
+def _check_phases(tr, waited):
+    by_id = {s.span_id: s for s in tr.spans}
+    disp = sorted(tr.find("dispatch"), key=lambda s: s.t0)
+    assert disp and len(waited) == len(disp)
+    for d, nbytes in zip(disp, waited):
+        kids = {s.name: s for s in tr.spans if s.parent_id == d.span_id}
+        launch = kids["dispatch.launch"]
+        wait, fetch = kids["dispatch.wait"], kids["dispatch.fetch"]
+        assert launch.t0 == d.t0 and launch.t1 == wait.t0
+        assert wait.t1 == fetch.t0 and fetch.t1 <= d.t1
+        assert fetch.attrs["bytes"] == nbytes > 0
+        assert by_id[d.parent_id].name == "step"
+    deliver = tr.find("deliver")
+    assert deliver and all(by_id[s.parent_id].name == "step"
+                           for s in deliver)
+    return disp
+
+
+def test_traced_dispatch_records_its_phases(rng, monkeypatch):
+    store = build_store(random_graph(rng), 1)
+    queries = [CHAIN, PAIR, CHAIN]
+    plain = ServeEngine(store, caps=TINY, max_escalations=3,
+                        metrics=MetricsRegistry()).execute(queries)
+    waited = _waited_on(monkeypatch)
+    tr = Tracer()
+    reg = MetricsRegistry()
+    eng = ServeEngine(store, caps=TINY, max_escalations=3, tracer=tr,
+                      metrics=reg)
+    traced = eng.execute(queries)
+    disp = _check_phases(tr, waited)
+    # one deliver per bucket, and each dispatch's copy is the host views
+    # the engine cut its answers from
+    assert len(tr.find("deliver")) == len(disp) == eng.dispatches
+    escalated = [d.attrs["escalated"] for d in disp]
+    assert sum(escalated) == reg.to_dict()["counters"][
+        "serve_escalations_total"] > 0
+    assert all(0 <= e <= d.attrs["n"] for e, d in zip(escalated, disp))
+    for a, b in zip(plain, traced):
+        assert a.rows_set() == b.rows_set() and len(a.rows) == len(b.rows)
+
+
+def test_traced_dispatch_phases_on_a_mesh(rng, monkeypatch):
+    store = build_store(random_graph(rng), 1)
+    waited = _waited_on(monkeypatch)
+    tr = Tracer()
+    eng = ServeEngine(store, cfg=ExecConfig(routing="a2a"), caps=ROOMY,
+                      mesh=_mesh1(), tracer=tr, metrics=MetricsRegistry())
+    eng.execute([CHAIN, PAIR])
+    disp = _check_phases(tr, waited)
+    assert all(d.attrs["escalated"] == 0 for d in disp)
+
+
+def test_untraced_dispatch_adds_no_sync(rng, monkeypatch):
+    store = build_store(random_graph(rng), 1)
+    waited = _waited_on(monkeypatch)
+    eng = ServeEngine(store, caps=ROOMY, metrics=MetricsRegistry())
+    res = eng.execute([CHAIN, PAIR])
+    assert waited == [] and eng.dispatches == 2
+    assert all(r.overflow == 0 for r in res)
+
+
+def test_cascade_steps_carry_their_name_scope(rng):
+    import re
+
+    import jax.numpy as jnp
+    store = build_store(random_graph(rng), 1)
+    eng = ServeEngine(store, caps=ROOMY, metrics=MetricsRegistry())
+    eng.execute([CHAIN])
+    (template,) = eng._template_ids
+    jitted, scratch_vars = eng._build(template, 1)
+    hlo = jitted.lower(
+        store.flat_keys(0), store.flat_keys(1),
+        jnp.zeros((1, template.n_consts), jnp.int32),
+        eng._scratch(scratch_vars, 1, template.steps[0].caps.out_cap),
+    ).compile().as_text()
+    scopes = set(re.findall(r"cascade/(s\d+_[a-z_]+)", hlo))
+    kinds = [st.kind for st in template.steps[1:]]
+    assert scopes == {"s0_scan"} | {f"s{i}_{k}"
+                                    for i, k in enumerate(kinds, 1)}
+
+
+# ---------------------------------------------------------------------------
 # metrics-off guarantee + per-tenant SLO counters
 # ---------------------------------------------------------------------------
 
