@@ -47,8 +47,15 @@ tenant asks "students of <their> department"). The engine exploits that:
   replans the query at geometrically escalated Caps (``escalate_caps``,
   bounded by ``max_escalations``) and re-enqueues it; the final attempt
   drops to the unrestricted planner's exact ``reduce_side`` fallback
-  via ``execute_local``. Per-query deadlines shed expired queries with
-  structured ``QueryTimeout`` results; a full queue sheds by priority
+  via ``execute_local``. A rung memory holds the highest rung each
+  query has escalated to, keyed on its base signature (patterns or plan,
+  cfg, base caps, ``store.layout_key`` — so an ingest starts it empty);
+  a repeat of the query enters the queue at that rung, on the plan and
+  compiled cascade the ladder built. A remembered rung that overflows
+  still escalates, the ladder's top and the fallback budget stay where
+  they were, and ``inexact_ok`` requests never read it. Per-query
+  deadlines shed expired queries with structured ``QueryTimeout``
+  results; a full queue sheds by priority
   (``QueryShed`` + ``retry_after``) before raising ``EngineBusy`` (which
   now carries the compiled plan and the hint); a seeded ``FaultPlan``
   injects drop/corrupt/delay faults into the a2a answer legs, which
@@ -286,7 +293,11 @@ class _Request:
     step_caps: tuple | None = None  # measured per-join-step answer caps
     patterns: tuple | None = None   # original patterns (escalation replans)
     ecaps: Caps | None = None       # effective caps this attempt runs at
-    attempt: int = 0                # completed overflow escalations so far
+    attempt: int = 0                # rung of the ladder this attempt runs
+                                    # at (0 = the base budget)
+    remembered: bool = False        # entered at `attempt` from the rung
+                                    # memory (no escalation got it there)
+    base_key: tuple | None = None   # base signature key (rung memory)
     deadline: float | None = None   # absolute deadline on the enq clock
     tenant: str | None = None       # shedding accounting key
     priority: int = 0               # higher wins under a full queue
@@ -407,6 +418,9 @@ class ServeEngine:
         self._t_last_dispatch: float | None = None
         self._compiled = LRUCache(compile_cache_size)
         self._signatures = LRUCache(max(4 * compile_cache_size, 64))
+        # rung memory (DESIGN.md §7): base signature key -> (rung, caps) of
+        # the highest escalation the query has needed
+        self._rungs = LRUCache(self._signatures.maxsize)
         # template interning: hashing a Template (a whole step tuple) per
         # scheduling decision is measurable python overhead at qps scale;
         # buckets key on a small int instead
@@ -455,6 +469,7 @@ class ServeEngine:
         self._m_disp_queries = reg.counter("serve_dispatched_queries_total")
         self._m_batch_hist = reg.histogram(
             "serve_batch_size", buckets=obs_metrics.DEFAULT_SIZE_BUCKETS)
+        self._m_rung_hits = reg.counter("serve_rung_memory_hits_total")
 
     def metrics(self) -> dict:
         """JSON snapshot of the engine's metrics registry: counters,
@@ -494,9 +509,11 @@ class ServeEngine:
         layout_key (which carries store_version) is part of the key too:
         a plan embeds MEASURED statistics and a2a capacities, so a
         post-ingest submit must re-plan rather than reuse a signature
-        computed against the pre-ingest store."""
+        computed against the pre-ingest store. The key is left in
+        ``_last_sig_key``: at the base caps it keys the rung memory."""
         sig_key = ("sig", plan if plan is not None else patterns,
                    self.cfg, caps, self.store.layout_key)
+        self._last_sig_key = sig_key
         hit = self._signatures.get(sig_key)
         self._last_plan_cached = hit is not None
         m = self.metrics_registry
@@ -595,6 +612,7 @@ class ServeEngine:
         tp0 = tr.now() if tr is not None else 0.0
         tid, template, consts, var_order, tuned, step_caps, est_cost = \
             self._signature_for(patterns, self.caps, plan=plan)
+        base_key = self._last_sig_key
         if tr is not None and not self._last_plan_cached:
             # plan spans only where planning actually ran; a cache hit is
             # one dict probe, carried as `template` on the submit span
@@ -641,6 +659,15 @@ class ServeEngine:
                               outcome="shed")
                 tr.end(victim.span, outcome="shed")
                 victim.span = None
+        # rung memory: a query whose exact answer needed an escalated
+        # budget enters at that rung, on the signature the ladder built
+        rung, ecaps = 0, self.caps
+        known = None if inexact_ok else self._rungs.get(base_key)
+        if known is not None:
+            rung, ecaps = known
+            tid, template, consts, var_order, tuned, step_caps, est_cost = \
+                self._signature_for(patterns, ecaps)
+            self._m_rung_hits.inc()
         rid = self._next_rid
         self._next_rid += 1
         enq = arrival if arrival is not None else time.monotonic()
@@ -660,9 +687,10 @@ class ServeEngine:
             tr._open[root.span_id] = root
         self._queue.append(_Request(
             rid, tid, template, consts, var_order, select, arrival, enq,
-            tuned, step_caps, patterns=patterns, ecaps=self.caps,
-            deadline=deadline, tenant=tenant, priority=priority,
-            inexact_ok=inexact_ok, est_cost=est_cost, span=root, tq0=tq0))
+            tuned, step_caps, patterns=patterns, ecaps=ecaps, attempt=rung,
+            remembered=rung > 0, base_key=base_key, deadline=deadline,
+            tenant=tenant, priority=priority, inexact_ok=inexact_ok,
+            est_cost=est_cost, span=root, tq0=tq0))
         c = self._m_requests.get(tenant)
         if c is None:
             c = self._m_requests[tenant] = m.counter(
@@ -1038,8 +1066,7 @@ class ServeEngine:
         caps = escalate_caps(r.ecaps if r.ecaps is not None else self.caps)
         self.fallbacks += 1
         self.metrics_registry.counter("serve_fallbacks_total").inc()
-        log.info("exact_fallback rid=%d after %d escalations", r.rid,
-                 r.attempt)
+        log.info("exact_fallback rid=%d at rung %d", r.rid, r.attempt)
         tr = self.tracer
         fsp = (tr.begin("exact_fallback", track="query", parent=r.span,
                         async_id=r.rid, attempt=r.attempt)
@@ -1076,10 +1103,15 @@ class ServeEngine:
         replan (new signature/template — escalated plans ride the same
         LRU caches, so a hot heavy-hitter template pays each budget's
         compile once), keep identity/deadline/enq so total latency and
-        deadline accounting span all attempts."""
+        deadline accounting span all attempts. The overflow proves every
+        rung up to this one too small for the query, so the rung memory
+        moves up to the new one."""
         ecaps = escalate_caps(r.ecaps if r.ecaps is not None else self.caps)
         tid, template, consts, var_order, tuned, step_caps, est_cost = \
             self._signature_for(r.patterns, ecaps)
+        known = self._rungs.get(r.base_key)
+        if known is None or known[0] <= r.attempt:
+            self._rungs[r.base_key] = (r.attempt + 1, ecaps)
         self.escalations += 1
         self.metrics_registry.counter("serve_escalations_total").inc()
         log.info("escalate rid=%d attempt=%d out_cap %d -> %d", r.rid,
@@ -1089,8 +1121,9 @@ class ServeEngine:
         self._queue.append(dataclasses.replace(
             r, tid=tid, template=template, consts=consts,
             var_order=var_order, tuned=tuned, step_caps=step_caps,
-            ecaps=ecaps, attempt=r.attempt + 1, prior_stats=stats,
-            est_cost=est_cost, tq0=tr.now() if tr is not None else 0.0))
+            ecaps=ecaps, attempt=r.attempt + 1, remembered=False,
+            prior_stats=stats, est_cost=est_cost,
+            tq0=tr.now() if tr is not None else 0.0))
 
     def _timeout(self, r: _Request, phase: str, now: float,
                  stats: dict | None = None) -> QueryTimeout:
@@ -1139,6 +1172,7 @@ class ServeEngine:
                 probe_b += pb
                 answer_b += ab
         tq = 0.0
+        remembered = 0
         if tr is not None:
             # bulk-materialize the queued-wait spans: ONE clock read and a
             # shared attrs dict per phase — this loop sits on the per-query
@@ -1147,8 +1181,10 @@ class ServeEngine:
             q_attrs: dict[str, dict] = {}
             append = tr.spans.append
             for r in reqs:
+                remembered += r.remembered
                 if r.span is not None and r.tq0 >= 0:
-                    key = "escalation" if r.attempt else "admit"
+                    key = ("escalation" if r.attempt and not r.remembered
+                           else "admit")
                     at = q_attrs.get(key)
                     if at is None:
                         at = q_attrs[key] = {"phase": key, "batch": batch}
@@ -1174,7 +1210,8 @@ class ServeEngine:
                             parent=self._step_span, template=reqs[0].tid,
                             batch=batch, n=n, epoch=epoch, retry=attempt,
                             faults=fsel is not None, bucket_cap=bucket_cap,
-                            probe_bytes=probe_b, answer_bytes=answer_b)
+                            probe_bytes=probe_b, answer_bytes=answer_b,
+                            remembered=remembered)
                    if tr is not None else None)
             # (S, batch, out_cap, nv) per-shard tables; S == 1 un-meshed
             tables, valids, overflow, step_ovf, bad = self._dispatch(
@@ -1407,8 +1444,8 @@ class ServeEngine:
             self._queue = deque(r for r in self._queue
                                 if r.rid not in gone)
             out.extend(self._timeout(
-                r, "escalation" if r.attempt > 0 else "queued", clock)
-                for r in expired)
+                r, "escalation" if r.attempt and not r.remembered
+                else "queued", clock) for r in expired)
             if not self._queue:
                 return out
         buckets: dict[int, list[_Request]] = {}

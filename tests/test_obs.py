@@ -236,6 +236,33 @@ def test_escalated_query_span_tree(rng):
     assert res.rows.shape[1] == 3     # ?x ?y ?z — the query still answers
 
 
+def test_remembered_rung_marks_the_dispatch_span(rng):
+    """A traced repeat of an escalated query: its one dispatch counts it
+    in `remembered`, escalates nothing, and its query spans show an admit
+    wait and the answering rung, with no rung0 escalate span."""
+    store = build_store(random_graph(rng), 1)
+    tr = Tracer()
+    reg = MetricsRegistry()
+    eng = ServeEngine(store, caps=TINY, max_escalations=8, tracer=tr,
+                      metrics=reg)
+    first = eng.execute([CHAIN])[0]
+    assert all(d.attrs["remembered"] == 0 for d in tr.find("dispatch"))
+    n0 = len(tr.spans)
+    second = eng.execute([CHAIN])[0]
+    new = tr.spans[n0:]
+    disp = [s for s in new if s.name == "dispatch"]
+    assert len(disp) == 1
+    assert disp[0].attrs["remembered"] == disp[0].attrs["n"] == 1
+    assert disp[0].attrs["escalated"] == 0
+    rungs = [s for s in new if s.name.startswith("rung")]
+    assert [s.attrs["outcome"] for s in rungs] == ["ok"]
+    assert rungs[0].attrs["attempt"] == first.stats["attempt"] > 0
+    assert [s.attrs["phase"] for s in new if s.name == "queued"] == [
+        "admit"]
+    assert reg.to_dict()["counters"]["serve_rung_memory_hits_total"] == 1
+    assert second.rows_set() == first.rows_set()
+
+
 def test_engine_trace_exports_loadable_json(rng, tmp_path):
     store = build_store(random_graph(rng), 1)
     tr = Tracer()

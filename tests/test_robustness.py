@@ -13,6 +13,7 @@ import pytest
 from repro.core import (Caps, ExecConfig, Pattern, build_store,
                         execute_local, execute_oracle, rows_set)
 from repro.core.planner import escalate_caps, next_cap, quantize_cap
+from repro.obs import MetricsRegistry
 from repro.serve import (EngineBusy, Fault, FaultPlan, QueryShed,
                          QueryTimeout, ServeEngine)
 
@@ -115,7 +116,7 @@ def test_escalations_bounded_then_exact(rng):
 
 
 def test_escalated_templates_reuse_compile_cache(rng):
-    """A second identical heavy query re-walks the escalation ladder but
+    """A second identical heavy query enters at its remembered rung and
     compiles nothing new: escalated plans ride the same LRU caches."""
     tr = random_graph(rng)
     store = build_store(tr, 1)
@@ -152,6 +153,143 @@ def test_bounded_inexact_mode_serves_capped_with_counters(rng):
     assert res[0].stats["degraded"] is True
     assert sum(res[0].stats["overflow_per_step"]) == res[0].overflow
     assert eng.escalations == 0 and eng.fallbacks == 0
+
+
+# ---------------------------------------------------------------------------
+# rung memory: a repeat of an escalated query enters at the rung it needed
+# ---------------------------------------------------------------------------
+
+
+def _rung_hits(reg) -> float:
+    return reg.counter("serve_rung_memory_hits_total").value
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["local", "mesh_a2a"])
+def test_rung_memory_answers_a_repeat_at_its_rung(rng, mesh):
+    """The first execute climbs the ladder; the second dispatches once, at
+    the remembered rung, on the cascade the ladder compiled — no
+    escalation, same rows, equal to the oracle. On the a2a mesh the
+    remembered budget reuses the escalated signature (re-embedded a2a
+    caps), not a new one."""
+    tr = random_graph(rng, n=150)
+    store = build_store(tr, 1)
+    want, ovars = execute_oracle(tr, CHAIN)
+    reg = MetricsRegistry()
+    kw = (dict(cfg=ExecConfig(routing="a2a"), mesh=_mesh1()) if mesh
+          else {})
+    eng = ServeEngine(store, caps=TINY, max_escalations=8, metrics=reg,
+                      **kw)
+    first = eng.execute([CHAIN])[0]
+    assert eng.escalations > 0 and eng.fallbacks == 0
+    assert _rung_hits(reg) == 0
+    e0, d0, sigs = eng.escalations, eng.dispatches, len(eng._signatures)
+    compiled = len(eng._compiled)
+    second = eng.execute([CHAIN])[0]
+    assert eng.escalations == e0                   # no re-climb
+    assert eng.dispatches == d0 + 1                # one dispatch, at the rung
+    assert _rung_hits(reg) == 1
+    assert len(eng._compiled) == compiled          # the ladder's cascade
+    assert len(eng._signatures) == sigs            # and its signature
+    assert second.stats["attempt"] == first.stats["attempt"] > 0
+    assert second.overflow == first.overflow == 0
+    assert second.rows_set(ovars) == first.rows_set(ovars) == want
+    assert len(second.rows) == len(first.rows)
+
+
+def test_rung_memory_starts_empty_after_an_ingest(rng, tmp_path):
+    """An ingest bumps store_version, which is part of the memory's key:
+    the repeat climbs the whole ladder again."""
+    from repro.store import MutableTripleStore
+    tr = np.unique(random_graph(rng), axis=0)
+    reg = MetricsRegistry()
+    st = MutableTripleStore.create(str(tmp_path / "s"), num_shards=1,
+                                   overlay_limit=4096)
+    st.ingest(tr)
+    eng = ServeEngine(st, caps=TINY, max_escalations=8, metrics=reg)
+    eng.execute([CHAIN])
+    climbed = eng.escalations
+    assert climbed > 0
+    extra = np.array([[0, 104, 0]], np.int32)      # answer unchanged
+    st.ingest(extra)
+    res = eng.execute([CHAIN])[0]
+    assert eng.escalations == 2 * climbed          # the same climb again
+    assert _rung_hits(reg) == 0
+    want, ovars = execute_oracle(np.concatenate([tr, extra]), CHAIN)
+    assert res.rows_set(ovars) == want and res.overflow == 0
+    st.close()
+
+
+def test_rung_memory_not_read_by_inexact_requests(rng):
+    """An inexact_ok submit of a remembered query runs at the base budget
+    and is served degraded, as on an engine that never saw it climb."""
+    store = build_store(random_graph(rng), 1)
+    reg = MetricsRegistry()
+    eng = ServeEngine(store, caps=TINY, max_escalations=8, metrics=reg)
+    eng.execute([CHAIN])
+    e0 = eng.escalations
+    eng.submit(CHAIN, inexact_ok=True)
+    res = eng.drain()[0]
+    fresh = ServeEngine(store, caps=TINY, metrics=MetricsRegistry())
+    fresh.submit(CHAIN, inexact_ok=True)
+    want = fresh.drain()[0]
+    assert res.stats["degraded"] is True and res.stats["attempt"] == 0
+    assert res.overflow == want.overflow > 0
+    assert res.rows_set() == want.rows_set()
+    assert eng.escalations == e0 and _rung_hits(reg) == 0
+
+
+def test_rung_memory_keeps_the_ladder_top(rng):
+    """A query whose ladder ends in the exact fallback is remembered at the
+    last engine rung: the repeat dispatches there once, overflows, and
+    falls back at the same budget as without the memory, with no
+    escalation counted."""
+    tr = random_graph(rng)
+    store = build_store(tr, 1)
+    want, ovars = execute_oracle(tr, CHAIN)
+    reg = MetricsRegistry()
+    eng = ServeEngine(store, caps=TINY, max_escalations=3, metrics=reg)
+    first = eng.execute([CHAIN])[0]                # no memory yet
+    assert first.stats["fallback"] == "reduce_side"
+    assert eng.escalations == 2 and eng.fallbacks == 1
+    d0 = eng.dispatches
+    second = eng.execute([CHAIN])[0]
+    assert second.stats["fallback"] == "reduce_side"
+    assert second.stats["attempt"] == first.stats["attempt"] == 2
+    assert second.stats["caps"] == first.stats["caps"]
+    assert eng.dispatches == d0 + 1 and eng.fallbacks == 2
+    assert eng.escalations == 2 and _rung_hits(reg) == 1
+    assert second.rows_set(ovars) == first.rows_set(ovars) == want
+
+
+def test_rung_memory_keys_on_the_constants(rng):
+    """One template, two constants: the heavy one climbs and is
+    remembered, its light siblings keep the base caps and still share one
+    base-budget dispatch."""
+    tr = np.array([(1, 101, 10 + y) for y in range(6)]
+                  + [(10 + y, 102, 50 + z) for y in range(6)
+                     for z in range(5)]
+                  + [(2, 101, 30), (30, 102, 60), (3, 101, 31),
+                     (31, 102, 61)], np.int32)
+    heavy = [Pattern(1, 101, "?y"), Pattern("?y", 102, "?z")]
+    light = [[Pattern(c, 101, "?y"), Pattern("?y", 102, "?z")]
+             for c in (2, 3)]
+    store = build_store(tr, 1)
+    reg = MetricsRegistry()
+    eng = ServeEngine(store, caps=TINY, max_escalations=8, metrics=reg)
+    base_tid = eng._signature_for(tuple(heavy), TINY)[0]
+    assert all(eng._signature_for(tuple(q), TINY)[0] == base_tid
+               for q in light)                     # one template
+    assert eng.execute([heavy])[0].stats["attempt"] > 0
+    e0, d0 = eng.escalations, eng.dispatches
+    res = eng.execute(light)
+    assert eng.dispatches == d0 + 1                # batched together
+    assert [r.stats["attempt"] for r in res] == [0, 0]
+    assert eng.escalations == e0 and _rung_hits(reg) == 0
+    for q, r in zip(light, res):
+        want, ovars = execute_oracle(tr, q)
+        assert r.rows_set(ovars) == want and r.overflow == 0
+    assert eng.execute([heavy])[0].stats["attempt"] > 0
+    assert eng.escalations == e0 and _rung_hits(reg) == 1
 
 
 # ---------------------------------------------------------------------------
